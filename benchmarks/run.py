@@ -21,6 +21,7 @@ import sys
 import traceback
 
 from benchmarks.common import Row, emit, write_json
+from repro.compile_cache import enable_compile_cache
 from repro.obs.logging_setup import (add_logging_args, get_logger,
                                      setup_from_args)
 
@@ -50,6 +51,7 @@ def main(argv=None) -> None:
     add_logging_args(ap)
     args = ap.parse_args(argv)
     setup_from_args(args)
+    enable_compile_cache()
     log = get_logger("repro.bench")
     log.info("name,us_per_call,derived")
     all_rows: list[Row] = []

@@ -6,8 +6,10 @@ lifts the client axis onto a ``("clients",)`` mesh axis (``repro.mesh``)
 — statics, mobility, draws, CC-MAB state and the candidate tables all
 run as (N/shards, M) shards, and budgeted selection merges per-shard
 heads with an ``all_gather`` champion reduce that is bitwise the dense
-walk. No accelerator needed to try it: this script splits the CPU into
-8 host devices (the flag must be set before jax is imported).
+walk. The client axis is split over every visible device: the four
+chips of a TPU v5e host, or — with no accelerator — 8 host devices the
+script carves out of the CPU (the flag must be set before jax is
+imported, and only the CPU backend reads it).
 
     PYTHONPATH=src python examples/sharded_cohort.py
 """
@@ -16,6 +18,7 @@ import os
 os.environ.setdefault(
     "XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
+import jax                                                   # noqa: E402
 import numpy as np                                           # noqa: E402
 
 import repro                                                 # noqa: E402
@@ -23,17 +26,19 @@ from repro import api                                        # noqa: E402
 
 
 def main():
+    shards = len(jax.devices())
     spec = api.ExperimentSpec(
         policy=api.PolicySpec("cocs"),
         env=api.EnvSpec("metropolis-100k", true_p="analytic"),
         train=api.TrainSpec(batch_size=16),
         eval=api.EvalSpec(eval_every=4),
         horizon=8, seeds=(0,),
-        shard=api.ShardSpec(clients=8),      # 8-way client shards
+        shard=api.ShardSpec(clients=shards),  # one client shard per device
         obs=repro.obs.ObsSpec(telemetry=True))
     n = 100_000
-    print(f"metropolis-100k: N={n} clients over an 8-way client mesh "
-          f"(12,500 clients/device), duty-cycled arrivals")
+    print(f"metropolis-100k: N={n} clients over a {shards}-way client mesh "
+          f"({n // shards:,} clients/device on "
+          f"{jax.devices()[0].platform}), duty-cycled arrivals")
     print("round-trip spec:",
           api.ExperimentSpec.from_json(spec.to_json()) == spec)
 

@@ -89,7 +89,9 @@ CIFAR10_NONCONVEX = HFLExperimentConfig(
     compute_low=8.0e6,
     compute_high=15.0e6,
     local_epochs=5,
-    lr=0.1,
+    # 0.1 overflows the CNN's local SGD within one round on the
+    # unit-variance synthetic 32x32x3 shards (tests/test_fed.py)
+    lr=0.02,
     utility="sqrt",
 )
 

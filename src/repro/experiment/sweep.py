@@ -42,7 +42,6 @@ import numpy as np
 from repro.core.utility import _policy_kwargs, realized_utility
 from repro.data.federated import FederatedDataset
 from repro.experiment.fused import fused_block, fused_block_device
-from repro.experiment.packing import slot_capacity
 from repro.fed.batched import (BatchedRoundEngine, bucketed_capacity,
                                make_round_spec)
 from repro.fed.hfl import _eval_fn
@@ -51,6 +50,10 @@ from repro.obs import trace as obs_trace
 from repro.policies.base import (FunctionalPolicy, PolicyAdapter, Round,
                                  rounds_to_scan_axes)
 from repro.policies.engine import (run_rounds_multi_seed, stack_states)
+
+# the synthetic fallback switches to the 16-d "tiny" kind from this many
+# clients on (metropolis-scale cohorts, dense or sharded alike)
+TINY_DATA_CLIENTS = 10_000
 
 
 @dataclass
@@ -145,10 +148,19 @@ def prepare_training(cfg, model_kind: str, batch_size: int,
                      aggregator: str = "mean", trim_frac: float = 0.1,
                      corrupt: bool = False) -> TrainingSetup:
     """Host-side training-state preparation shared by every fused path:
-    synthetic-data default (shared ``seed=0`` dataset), stacked shards,
+    synthetic-data default (shared ``seed=0`` dataset; ``"tiny"`` from
+    ``TINY_DATA_CLIENTS`` clients on), stacked shards,
     per-seed model inits broadcast to (M, ...) edge params, per-seed
     sampler base keys, and the static round spec."""
     kind = "mnist" if model_kind.startswith("logreg") else "cifar"
+    if data is None and kind == "mnist" and \
+            cfg.num_clients >= TINY_DATA_CLIENTS:
+        # metropolis scale: 784-d shards would need terabytes
+        with obs_trace.span("data.synthetic_tiny",
+                            clients=cfg.num_clients):
+            data = FederatedDataset.synthetic(
+                cfg.num_clients, kind="tiny", samples_per_client=20,
+                seed=0)
     data = data or FederatedDataset.synthetic(cfg.num_clients, kind=kind,
                                               seed=0)
     stacked = data.stacked()
@@ -514,36 +526,19 @@ def sweep_experiments(policies: Union[Sequence[str],
             if slots_per_es is not None:
                 slots_blocks = [int(slots_per_es)] * len(ends)
             else:
-                # bandit pre-scan (~ms): exact per-block slot capacity,
-                # falling back to the budget bound if the pre-scan fails
-                # (surfaced — padding then costs perf, never correctness)
-                try:
-                    with obs_trace.span("slots.prescan", policy=name):
-                        if device_env:
-                            from repro.sim.engine import run_bandit_device
-                            pre = run_bandit_device(pol, env.spec, seeds,
-                                                    horizon,
-                                                    policy_seeds=pol_seeds)
-                        else:
-                            pre = run_rounds_multi_seed(pol, batch_st,
-                                                        pol_seeds)
-                    slots_blocks = _block_slots(
-                        pre["selections"], cfg.num_edge_servers, ends,
-                        spec.slot_bucket)
-                except Exception as e:  # noqa: BLE001 — degrade, don't die
-                    warnings.warn(
-                        f"bandit pre-scan failed for {name} "
-                        f"({type(e).__name__}: {e}); using the budget "
-                        "slot bound instead of exact per-block capacity",
-                        stacklevel=2)
-                    # the policy's own budget (it may override the env's):
-                    # the bound must cover whatever its solver can pack
-                    min_cost = (env.spec.min_cost() if device_env
-                                else float(np.min(
-                                    np.asarray(batch_st.costs))))
-                    slots_blocks = [slot_capacity(
-                        pol.spec.budget, min_cost,
-                        cfg.num_clients)] * len(ends)
+                # bandit pre-scan (~ms): exact per-block slot capacity
+                with obs_trace.span("slots.prescan", policy=name):
+                    if device_env:
+                        from repro.sim.engine import run_bandit_device
+                        pre = run_bandit_device(pol, env.spec, seeds,
+                                                horizon,
+                                                policy_seeds=pol_seeds)
+                    else:
+                        pre = run_rounds_multi_seed(pol, batch_st,
+                                                    pol_seeds)
+                slots_blocks = _block_slots(
+                    pre["selections"], cfg.num_edge_servers, ends,
+                    spec.slot_bucket)
             ctx = None
             if resilient:
                 pdir = None

@@ -7,7 +7,7 @@ cloud-level aggregation.
 """
 from __future__ import annotations
 
-from typing import Any, Tuple
+from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -49,7 +49,8 @@ def effective_mask_multi(arrived: jax.Array, tau: jax.Array,
 def deadline_masked_aggregate(edge_params: Any, deltas: Any,
                               arrived: jax.Array, tau: jax.Array,
                               z_min: int = 1, use_kernel: bool = False,
-                              tile: int = 512, interpret: bool = True
+                              tile: int = 512,
+                              interpret: Optional[bool] = None
                               ) -> Tuple[Any, jax.Array]:
     """deltas: pytree with leading client axis (C, ...). Returns updated edge
     params (Eq. 3 restricted to the effective mask) + number of contributors.

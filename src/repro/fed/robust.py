@@ -31,7 +31,7 @@ exactly like the mean path.
 """
 from __future__ import annotations
 
-from typing import Any
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -100,7 +100,7 @@ def robust_aggregate_stacked(edge_params: Any, deltas: Any,
                              aggregator: str = "mean",
                              trim_frac: float = 0.1,
                              use_kernel: bool = False, tile: int = 512,
-                             interpret: bool = True) -> Any:
+                             interpret: Optional[bool] = None) -> Any:
     """Eq. 3 over all edge servers with a selectable aggregation rule.
 
     Same contract as ``masked_aggregate_stacked``: ``edge_params`` pytree

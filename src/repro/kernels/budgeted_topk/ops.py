@@ -25,8 +25,7 @@ recomputing gains per iteration — bitwise-identical to
 from __future__ import annotations
 
 import functools
-import time
-from typing import Callable, NamedTuple, Tuple
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -35,6 +34,7 @@ from jax import lax
 from repro.kernels.budgeted_topk.kernel import density_sort_kernel
 from repro.kernels.budgeted_topk.ref import (pair_density,
                                              sorted_candidates_ref)
+from repro.kernels.common import fastest
 
 DEFAULT_TILE = 128
 
@@ -57,29 +57,22 @@ def best_tile(num_clients: int, num_es: int,
     about the lowered kernel). Cached per (N, M)."""
     if jax.default_backend() != "tpu":
         return DEFAULT_TILE
-    key = jax.random.PRNGKey(0)
     n, m = max(int(num_clients), 1), max(int(num_es), 1)
-    values = jax.random.uniform(key, (n, m), jnp.float32)
-    costs = jnp.full((n,), 0.5, jnp.float32)
-    eligible = jnp.ones((n, m), bool)
-    best_us, pick = None, DEFAULT_TILE
-    for tile in candidates:
-        def call(tile=tile):
-            return density_sort_kernel(values, costs, eligible, tile=tile,
-                                       interpret=False)
-        jax.block_until_ready(call())         # compile
-        t0 = time.perf_counter()
-        for _ in range(3):
-            jax.block_until_ready(call())
-        dt = (time.perf_counter() - t0) / 3
-        if best_us is None or dt < best_us:
-            best_us, pick = dt, tile
-    return pick
+
+    def inputs():
+        return (jax.random.uniform(jax.random.PRNGKey(0), (n, m),
+                                   jnp.float32),
+                jnp.full((n,), 0.5, jnp.float32),
+                jnp.ones((n, m), bool))
+
+    return fastest(candidates, inputs,
+                   lambda tile, *a: density_sort_kernel(
+                       *a, tile=tile, interpret=False))
 
 
 def sorted_candidates(values: jax.Array, costs: jax.Array,
                       eligible: jax.Array, use_kernel: bool = False,
-                      tile: int = 0, interpret: bool = True
+                      tile: int = 0, interpret: Optional[bool] = None
                       ) -> Tuple[jax.Array, jax.Array]:
     """(density, flat_index) segments, each row sorted (density desc,
     index desc): (num_tiles, P) from the Pallas kernel, or one (1, N*M)
@@ -113,7 +106,7 @@ class Segments(NamedTuple):
 
 def build_segments(values: jax.Array, costs: jax.Array, eligible: jax.Array,
                    base=0, use_kernel: bool = False, tile: int = 0,
-                   interpret: bool = True) -> Segments:
+                   interpret: Optional[bool] = None) -> Segments:
     """Sorted candidate ``Segments`` over a (possibly shard-local)
     ``(n, M)`` block whose rows are global clients ``base .. base+n``.
     ``base`` may be traced (``axis_index * n_local`` under shard_map)."""
@@ -138,7 +131,7 @@ def identity_segments(values: jax.Array, costs: jax.Array,
     every column per iteration anyway, and P2 consumes this layout with
     ``sorted_rows=False`` (masked max instead of first-feasible scan),
     which picks the identical head. This is the layout the sharded
-    cohort engine uses *inside* ``shard_map``: with ``check_rep=False``
+    cohort engine uses *inside* ``shard_map``: with ``check_vma=False``
     the SPMD partitioner loses the manual-sharding annotation on
     ``lax.sort`` and re-partitions it as a global sharded sort —
     inserting cross-shard all-reduces that sum per-shard tables into
@@ -298,7 +291,8 @@ def flgreedy_walk(segs: Segments, budgets: jax.Array, *, num_es: int,
                                              "interpret"))
 def budgeted_topk(values: jax.Array, costs: jax.Array, budgets: jax.Array,
                   eligible: jax.Array, use_kernel: bool = False,
-                  tile: int = 0, interpret: bool = True) -> jax.Array:
+                  tile: int = 0, interpret: Optional[bool] = None
+                  ) -> jax.Array:
     """Density greedy for P2 over sorted candidates. values (N, M),
     costs (N,), budgets (M,), eligible (N, M) bool -> assign (N,) int32
     (-1 = unselected); bitwise-identical to ``greedy_assign``."""
@@ -315,7 +309,7 @@ def budgeted_topk(values: jax.Array, costs: jax.Array, budgets: jax.Array,
 def flgreedy_topk(values: jax.Array, costs: jax.Array, budgets: jax.Array,
                   eligible: jax.Array, num_es: int = 0,
                   use_kernel: bool = False, tile: int = 0,
-                  interpret: bool = True) -> jax.Array:
+                  interpret: Optional[bool] = None) -> jax.Array:
     """Cost-benefit greedy for P3 (Eq. 19 sqrt utility) over the same
     compressed sorted layout; bitwise-identical to ``flgreedy_assign``."""
     n, m = values.shape

@@ -27,12 +27,14 @@ share one float32 primitive sequence and agree bitwise.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.network import path_loss_gain
+from repro.kernels.common import resolve_interpret
 from repro.kernels.context_pairwise.ref import (PairwiseContext, latency,
                                                shannon_rate)
 
@@ -61,7 +63,7 @@ def _kernel(pos_ref, es_ref, bw_ref, comp_ref, fdt_ref, fut_ref,
     "tx_w", "noise_psd_w", "update_bits", "workload", "tile", "interpret"))
 def context_pairwise_kernel(pos, es, bandwidth, compute, fad_dt, fad_ut, *,
                             tx_w, noise_psd_w, update_bits, workload,
-                            tile: int = 128, interpret: bool = True
+                            tile: int = 128, interpret: Optional[bool] = None
                             ) -> PairwiseContext:
     """Same signature/semantics as ``pairwise_context_ref`` (modulo the
     static tile/interpret knobs)."""
@@ -92,7 +94,7 @@ def context_pairwise_kernel(pos, es, bandwidth, compute, fad_dt, fad_ut, *,
         ],
         out_specs=[tile_nm, tile_nm, tile_nm, tile_nm],
         out_shape=[jax.ShapeDtypeStruct((np_, m), f32)] * 4,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(pos.astype(f32), es.astype(f32),
       bandwidth.reshape(np_, 1).astype(f32),
       compute.reshape(np_, 1).astype(f32),

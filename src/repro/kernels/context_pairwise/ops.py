@@ -14,12 +14,12 @@ share the fleet-wide convention.
 from __future__ import annotations
 
 import functools
-import time
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.common import fastest
 from repro.kernels.context_pairwise.kernel import context_pairwise_kernel
 from repro.kernels.context_pairwise.ref import (PairwiseContext,
                                                pairwise_context_ref)
@@ -37,34 +37,26 @@ def best_tile(num_clients: int, num_es: int,
     returned without timing. Cached per (N, M)."""
     if jax.default_backend() != "tpu":
         return DEFAULT_TILE
-    key = jax.random.PRNGKey(0)
     n, m = max(int(num_clients), 1), max(int(num_es), 1)
-    pos = jax.random.uniform(key, (n, 2), jnp.float32, -1.5, 1.5)
-    es = jax.random.uniform(key, (m, 2), jnp.float32, -1.5, 1.5)
-    bw = jnp.full((n,), 1e6, jnp.float32)
-    comp = jnp.full((n,), 1e9, jnp.float32)
-    fad = jnp.ones((n, m), jnp.float32)
-    best_us, pick = None, DEFAULT_TILE
-    for tile in candidates:
-        def call(tile=tile):
-            return context_pairwise_kernel(
-                pos, es, bw, comp, fad, fad, tx_w=0.2,
-                noise_psd_w=3.98e-21, update_bits=1e5, workload=1e7,
-                tile=tile, interpret=False)
-        jax.block_until_ready(call())         # compile
-        t0 = time.perf_counter()
-        for _ in range(3):
-            jax.block_until_ready(call())
-        dt = (time.perf_counter() - t0) / 3
-        if best_us is None or dt < best_us:
-            best_us, pick = dt, tile
-    return pick
+
+    def inputs():
+        key = jax.random.PRNGKey(0)
+        fad = jnp.ones((n, m), jnp.float32)
+        return (jax.random.uniform(key, (n, 2), jnp.float32, -1.5, 1.5),
+                jax.random.uniform(key, (m, 2), jnp.float32, -1.5, 1.5),
+                jnp.full((n,), 1e6, jnp.float32),
+                jnp.full((n,), 1e9, jnp.float32), fad, fad)
+
+    return fastest(candidates, inputs,
+                   lambda tile, *a: context_pairwise_kernel(
+                       *a, tx_w=0.2, noise_psd_w=3.98e-21, update_bits=1e5,
+                       workload=1e7, tile=tile, interpret=False))
 
 
 def pairwise_context(pos, es, bandwidth, compute, fad_dt, fad_ut, *,
                      tx_w, noise_psd_w, update_bits, workload,
                      use_kernel: bool = False, tile: int = 0,
-                     interpret: bool = True) -> PairwiseContext:
+                     interpret: Optional[bool] = None) -> PairwiseContext:
     """pos (N, 2), es (M, 2), bandwidth/compute (N,), fad_dt/fad_ut
     (N, M) -> ``PairwiseContext`` of four (N, M) float32 tensors.
 

@@ -14,11 +14,14 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.common import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -84,7 +87,7 @@ def flash_attention_kernel(q: jax.Array, k: jax.Array, v: jax.Array,
                            causal: bool = True, window: int = 0,
                            sm_scale: float = 0.0, block_q: int = 128,
                            block_k: int = 128,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: Optional[bool] = None) -> jax.Array:
     """q: (B, H, S, D); k, v: (B, KV, S, D) with H % KV == 0. Returns like q."""
     b, h, s, d = q.shape
     kv = k.shape[1]
@@ -126,6 +129,6 @@ def flash_attention_kernel(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((block_q, 1), jnp.float32),   # running denom l
             pltpu.VMEM((block_q, d), jnp.float32),   # output accumulator
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)
     return out[:, :, :s]

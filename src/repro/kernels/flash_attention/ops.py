@@ -8,6 +8,7 @@ handles layout transposition.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 
@@ -20,7 +21,7 @@ from repro.kernels.flash_attention.ref import attention_ref
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True, window: int = 0,
                     use_kernel: bool = True,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: Optional[bool] = None) -> jax.Array:
     """q: (B, S, H, D); k, v: (B, S, KV, D) — model layout. Returns like q."""
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)

@@ -11,10 +11,13 @@ matmul. TILE is a multiple of 128 for lane alignment.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.common import resolve_interpret
 
 
 def _kernel(w_ref, denom_ref, delta_ref, param_ref, out_ref):
@@ -22,14 +25,15 @@ def _kernel(w_ref, denom_ref, delta_ref, param_ref, out_ref):
     d = delta_ref[...].astype(jnp.float32)        # (C, T)
     p = param_ref[...].astype(jnp.float32)        # (1, T)
     denom = denom_ref[0, 0]
-    agg = jax.lax.dot(w, d) / denom               # (1, T) on the MXU
+    # HIGHEST: TPU's default f32 matmul rounds the deltas to bfloat16
+    agg = jax.lax.dot(w, d, precision=jax.lax.Precision.HIGHEST) / denom
     out_ref[...] = (p + agg).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
 def masked_aggregate_kernel(param: jax.Array, deltas: jax.Array,
                             weights: jax.Array, tile: int = 512,
-                            interpret: bool = True) -> jax.Array:
+                            interpret: Optional[bool] = None) -> jax.Array:
     """param: (D,); deltas: (C, D); weights: (C,). Returns (D,)."""
     c, d = deltas.shape
     pad = (-d) % tile
@@ -50,6 +54,6 @@ def masked_aggregate_kernel(param: jax.Array, deltas: jax.Array,
         ],
         out_specs=pl.BlockSpec((1, tile), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, dp), param.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(w2, denom, deltas, param.reshape(1, dp))
     return out.reshape(dp)[:d]

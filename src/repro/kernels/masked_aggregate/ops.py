@@ -22,12 +22,12 @@ take its pick instead of a hardcoded 512.
 from __future__ import annotations
 
 import functools
-import time
-from typing import Any, Tuple
+from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.common import fastest
 from repro.kernels.masked_aggregate.kernel import masked_aggregate_kernel
 from repro.kernels.masked_aggregate.ref import (masked_aggregate_ref,
                                                masked_aggregate_ref_stacked)
@@ -48,30 +48,24 @@ def best_tile(param_count: int,
     """
     if jax.default_backend() != "tpu":
         return DEFAULT_TILE
-    c = 16
     d = max(int(param_count), max(candidates))
-    key = jax.random.PRNGKey(0)
-    param = jnp.zeros((d,), jnp.float32)
-    deltas = jax.random.normal(key, (c, d), jnp.float32)
-    w = jnp.ones((c,), jnp.float32)
-    best_us, pick = None, DEFAULT_TILE
-    for tile in candidates:
-        def call(tile=tile):
-            return masked_aggregate_kernel(param, deltas, w, tile=tile,
-                                           interpret=False)
-        call().block_until_ready()            # compile
-        t0 = time.perf_counter()
-        for _ in range(3):
-            call().block_until_ready()
-        dt = (time.perf_counter() - t0) / 3
-        if best_us is None or dt < best_us:
-            best_us, pick = dt, tile
-    return pick
+
+    def inputs():
+        c = 16
+        return (jnp.zeros((d,), jnp.float32),
+                jax.random.normal(jax.random.PRNGKey(0), (c, d),
+                                  jnp.float32),
+                jnp.ones((c,), jnp.float32))
+
+    return fastest(candidates, inputs,
+                   lambda tile, *a: masked_aggregate_kernel(
+                       *a, tile=tile, interpret=False))
 
 
 def masked_aggregate_flat(param: jax.Array, deltas: jax.Array,
                           weights: jax.Array, use_kernel: bool = False,
-                          tile: int = 512, interpret: bool = True
+                          tile: int = 512,
+                          interpret: Optional[bool] = None
                           ) -> jax.Array:
     """param: (D,); deltas: (C, D); weights: (C,). Returns (D,)."""
     if use_kernel:
@@ -82,7 +76,7 @@ def masked_aggregate_flat(param: jax.Array, deltas: jax.Array,
 
 def masked_aggregate(edge_params: Any, deltas: Any, weights: jax.Array,
                      use_kernel: bool = False, tile: int = 512,
-                     interpret: bool = True) -> Any:
+                     interpret: Optional[bool] = None) -> Any:
     """edge_params: pytree; deltas: same pytree with leading client axis (C,);
     weights: (C,) participation mask/weights."""
     leaves_p, treedef = jax.tree.flatten(edge_params)
@@ -99,7 +93,8 @@ def masked_aggregate(edge_params: Any, deltas: Any, weights: jax.Array,
 
 def masked_aggregate_stacked(edge_params: Any, deltas: Any,
                              weights: jax.Array, use_kernel: bool = False,
-                             tile: int = 512, interpret: bool = True) -> Any:
+                             tile: int = 512,
+                             interpret: Optional[bool] = None) -> Any:
     """Aggregate every edge server in one shot (batched HFL round hot spot).
 
     edge_params: pytree, leaves (M, ...); deltas: same pytree, leaves

@@ -9,7 +9,8 @@ def masked_aggregate_ref(param: jax.Array, deltas: jax.Array,
                          weights: jax.Array) -> jax.Array:
     w = weights.astype(jnp.float32)
     denom = jnp.maximum(jnp.sum(w), 1.0)
-    agg = jnp.einsum("c,cd->d", w, deltas.astype(jnp.float32)) / denom
+    agg = jnp.einsum("c,cd->d", w, deltas.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST) / denom
     return (param.astype(jnp.float32) + agg).astype(param.dtype)
 
 
@@ -21,6 +22,8 @@ def masked_aggregate_ref_stacked(params: jax.Array, deltas: jax.Array,
     each row aggregated under its own mask/denominator (max(sum w_m, 1))."""
     w = weights.astype(jnp.float32)
     denom = jnp.maximum(jnp.sum(w, axis=1), 1.0)
-    agg = jnp.einsum("ms,msd->md", w, deltas.astype(jnp.float32))
+    # HIGHEST: TPU's default f32 matmul rounds the deltas to bfloat16
+    agg = jnp.einsum("ms,msd->md", w, deltas.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
     return (params.astype(jnp.float32)
             + agg / denom[:, None]).astype(params.dtype)

@@ -9,10 +9,13 @@ renormalized to sum to 1 (the combine convention used by moe_block).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.common import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -41,7 +44,7 @@ def _kernel(logits_ref, gates_ref, idx_ref, *, top_k: int):
 
 @functools.partial(jax.jit, static_argnames=("top_k", "tile", "interpret"))
 def moe_router_kernel(logits: jax.Array, top_k: int, tile: int = 256,
-                      interpret: bool = True):
+                      interpret: Optional[bool] = None):
     """logits: (T, E) fp32/bf16. Returns (gates (T,k) f32, idx (T,k) i32)."""
     t, e = logits.shape
     tile = min(tile, t)
@@ -63,6 +66,6 @@ def moe_router_kernel(logits: jax.Array, top_k: int, tile: int = 256,
             jax.ShapeDtypeStruct((tp, top_k), jnp.float32),
             jax.ShapeDtypeStruct((tp, top_k), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(logits)
     return gates[:t], idx[:t]

@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 
@@ -12,7 +13,7 @@ from repro.kernels.moe_router.ref import moe_router_ref
 @functools.partial(jax.jit, static_argnames=("top_k", "use_kernel",
                                              "interpret"))
 def moe_router(logits: jax.Array, top_k: int, use_kernel: bool = True,
-               interpret: bool = True):
+               interpret: Optional[bool] = None):
     """logits: (T, E). Returns (gates (T, k) f32, expert idx (T, k) i32)."""
     if use_kernel:
         return moe_router_kernel(logits, top_k, interpret=interpret)

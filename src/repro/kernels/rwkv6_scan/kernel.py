@@ -14,11 +14,14 @@ fp32 range for realistic decays.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.common import resolve_interpret
 
 
 def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, y_ref, fin_ref, state_scr, *,
@@ -60,7 +63,7 @@ def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, y_ref, fin_ref, state_scr, *,
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def rwkv6_scan_kernel(r: jax.Array, k: jax.Array, v: jax.Array,
                       log_w: jax.Array, u: jax.Array, chunk: int = 64,
-                      interpret: bool = True):
+                      interpret: Optional[bool] = None):
     """r,k,log_w: (B,H,T,dk); v: (B,H,T,dv); u: (H,dk).
     Returns (y (B,H,T,dv) fp32, final_state (B,H,dk,dv) fp32)."""
     b, h, t, dk = r.shape
@@ -88,6 +91,6 @@ def rwkv6_scan_kernel(r: jax.Array, k: jax.Array, v: jax.Array,
             jax.ShapeDtypeStruct((b, h, dk, dv), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(r, k, v, log_w, u)
     return y, fin

@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 
@@ -12,7 +13,7 @@ from repro.kernels.rwkv6_scan.ref import rwkv6_scan_ref
 @functools.partial(jax.jit, static_argnames=("chunk", "use_kernel",
                                              "interpret"))
 def rwkv6_scan(r, k, v, log_w, u, chunk: int = 64, use_kernel: bool = True,
-               interpret: bool = True):
+               interpret: Optional[bool] = None):
     """r,k,log_w: (B,H,T,dk); v: (B,H,T,dv); u: (H,dk)."""
     if use_kernel:
         return rwkv6_scan_kernel(r, k, v, log_w, u, chunk=chunk,

@@ -198,7 +198,7 @@ def dryrun_one(arch: str, shape_name: str, multi_pod: bool = False,
                                    if mode in ("train", "hfl") else 1)
     n_active = active_param_count(cfg)
 
-    with mesh:
+    with jax.sharding.set_mesh(mesh):
         compiled = _lower(cfg, shape, mode, mesh=mesh, n_edge=n_edge,
                           microbatch=microbatch).compile()
     mem = _mem_stats(compiled)

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import envs, policies
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ARCH_IDS, get_config
 from repro.configs.paper_hfl import CIFAR10_NONCONVEX, MNIST_CONVEX
 from repro.data.tokens import client_token_shards
@@ -99,6 +100,7 @@ def main(argv=None) -> int:
     ap.add_argument("--scenario", default="paper",
                     choices=sorted(envs.SCENARIOS))
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.paper:
         return run_paper(args)
     if args.arch:

@@ -35,7 +35,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.experiment.fused import BlockOut, _block_eval, _swap
@@ -74,7 +73,7 @@ def _mask_topz(arrived, tau, valid, z_min: int):
     """``fed.edge.effective_mask_multi`` without ``lax.top_k``.
 
     ``top_k`` lowers to ``lax.sort``, which the SPMD partitioner
-    mis-partitions inside a ``check_rep=False`` shard_map body (see
+    mis-partitions inside a ``check_vma=False`` shard_map body (see
     ``repro.mesh.select``); the Z-fastest fallback set is recovered
     instead by pairwise slot ranks — ``rank_i = #{j : (tau_j, j) <
     (tau_i, i)}`` — which reproduces ``top_k(-tau, z)``'s
@@ -313,8 +312,8 @@ def sharded_block_device(policy: FunctionalPolicy, spec: BatchedRoundSpec,
             accuracy=so, loss=so, env_pos=sc,
             telemetry=tele_frame_spec,
             tele_acc=(_tree_spec(acc_init(1), so) if telemetry else None))
-        fn = shard_map(body, mesh=mesh, in_specs=specs_in,
-                       out_specs=specs_out, check_rep=False)
+        fn = jax.shard_map(body, mesh=mesh, in_specs=specs_in,
+                           out_specs=specs_out, check_vma=False)
         return fn(stacked_x, stacked_y, stacked_sizes, base_keys,
                   policy_state, edge_params, env_pos, seeds, statics,
                   ts, test_x, test_y)

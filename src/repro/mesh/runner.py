@@ -11,10 +11,11 @@ matches to float tolerance (cross-shard sum reassociation).
 Scale notes: slot capacity comes from the analytic budget bound
 (``slot_capacity``), not the dense bandit pre-scan — a pre-scan would
 materialize the (N,) policy walk the mesh exists to avoid. Synthetic
-fallback data switches to the 16-d ``"tiny"`` kind at metropolis scale
-(>= 10^4 clients); the returned selections are still dense (S, T, N) on
-host, which at 10^6 clients is the dominant host allocation (~0.8 GB
-per 200 rounds) — slice horizons accordingly.
+fallback data is ``prepare_training``'s, shared with the dense tiers
+(the 16-d ``"tiny"`` kind at metropolis scale); the returned selections
+are still dense (S, T, N) on host, which at 10^6 clients is the
+dominant host allocation (~0.8 GB per 200 rounds) — slice horizons
+accordingly.
 """
 from __future__ import annotations
 
@@ -34,9 +35,6 @@ from repro.mesh.topology import cohort_mesh, shard_layouts
 from repro.obs import trace as obs_trace
 from repro.policies.base import FunctionalPolicy
 from repro.policies.engine import stack_states
-
-TINY_DATA_CLIENTS = 10_000     # synthetic fallback switches to "tiny"
-
 
 def _validate(env, shard, num_clients: int, n_seeds: int, model_kind: str):
     from repro.sim.core import DeviceEnv
@@ -86,12 +84,6 @@ def sweep_sharded(policies: Dict[str, FunctionalPolicy], env,
                      seed_shards=shard.seeds, client_shards=shard.clients)
     pol_seeds = [s + int(policy_seed_offset) for s in seeds]
 
-    if data is None and cfg.num_clients >= TINY_DATA_CLIENTS:
-        with obs_trace.span("data.synthetic_tiny",
-                            clients=cfg.num_clients):
-            data = FederatedDataset.synthetic(
-                cfg.num_clients, kind="tiny", samples_per_client=20,
-                seed=0)
     with obs_trace.span("train.prepare", seeds=len(seeds),
                         model=model_kind, sharded=True):
         setup = prepare_training(cfg, model_kind, batch_size,

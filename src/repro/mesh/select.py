@@ -26,6 +26,7 @@ Two entry points share the walk in ``kernels.budgeted_topk.ops``:
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -66,7 +67,8 @@ def shard_assign(values: jax.Array, costs: jax.Array, eligible: jax.Array,
                  budgets: jax.Array, *, axis_name: str, num_clients: int,
                  sqrt_utility: bool = False, num_es_div: int = 0,
                  sync_axes: tuple = (), use_kernel: bool = False,
-                 tile: int = 0, interpret: bool = True) -> jax.Array:
+                 tile: int = 0, interpret: Optional[bool] = None
+                 ) -> jax.Array:
     """One shard's half of the hierarchical selection (inside shard_map).
 
     values/eligible (n_local, M), costs (n_local,): this shard's rows of
@@ -82,7 +84,7 @@ def shard_assign(values: jax.Array, costs: jax.Array, eligible: jax.Array,
     no-op iterations until the whole mesh is done.
 
     The jnp path deliberately avoids ``lax.sort``: inside a
-    ``check_rep=False`` shard_map body the SPMD partitioner drops the
+    ``check_vma=False`` shard_map body the SPMD partitioner drops the
     sort's manual-sharding annotation and re-partitions it as a global
     sharded sort, inserting cross-shard all-reduces that *sum* the
     per-shard tables into garbage (reproduced on multi-device CPU
@@ -129,7 +131,7 @@ def shard_assign(values: jax.Array, costs: jax.Array, eligible: jax.Array,
 
 def shard_segments(values: jax.Array, costs: jax.Array, eligible: jax.Array,
                    num_shards: int, use_kernel: bool = False, tile: int = 0,
-                   interpret: bool = True) -> Segments:
+                   interpret: Optional[bool] = None) -> Segments:
     """Per-shard sorted segments of a dense (N, M) table, stacked: what
     ``num_shards`` mesh shards would each build locally, with globally
     addressed flat indices and global ``loc`` rows (the emulation walks
@@ -164,7 +166,8 @@ def _pad_clients(values, costs, eligible, num_shards: int):
 def hier_greedy_assign(values: jax.Array, costs: jax.Array,
                        budgets: jax.Array, eligible: jax.Array,
                        num_shards: int = 1, use_kernel: bool = False,
-                       tile: int = 0, interpret: bool = True) -> jax.Array:
+                       tile: int = 0, interpret: Optional[bool] = None
+                  ) -> jax.Array:
     """P2 density greedy over ``num_shards`` per-shard segment sets —
     bitwise ``greedy_assign`` at any shard count. N that does not divide
     evenly is padded with ineligible rows (a real mesh pads the same
@@ -187,7 +190,7 @@ def hier_flgreedy_assign(values: jax.Array, costs: jax.Array,
                          budgets: jax.Array, eligible: jax.Array,
                          num_shards: int = 1, num_es: int = 0,
                          use_kernel: bool = False, tile: int = 0,
-                         interpret: bool = True) -> jax.Array:
+                         interpret: Optional[bool] = None) -> jax.Array:
     """P3 sqrt-utility cost-benefit greedy over per-shard segments —
     bitwise ``flgreedy_assign`` at any shard count."""
     m = values.shape[1]

@@ -1,7 +1,7 @@
 """Guarded with_sharding_constraint helpers.
 
 Model code calls ``hint(x, spec...)`` at layout-critical points (logits,
-MoE dispatch). Under a mesh context (pjit lowering) the constraint is
+MoE dispatch). Under a ``jax.sharding.set_mesh`` context the constraint is
 applied with unavailable/non-divisible axes dropped; outside a mesh (CPU
 smoke tests) it is a no-op.
 """
@@ -16,23 +16,9 @@ Axis = Union[None, str, Tuple[str, ...]]
 
 
 def _current_mesh():
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and not m.empty:
-            return m
-    except Exception:  # noqa: BLE001
-        pass
-    try:
-        import warnings
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore", DeprecationWarning)
-            from jax.interpreters import pxla
-            m = pxla.thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except Exception:  # noqa: BLE001
-        pass
-    return None
+    """The mesh set by ``jax.sharding.set_mesh``, or None outside one."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def _filter_axis(axis: Axis, dim: int, mesh) -> Axis:

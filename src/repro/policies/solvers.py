@@ -62,12 +62,11 @@ def greedy_assign(values: jax.Array, costs: jax.Array, budgets: jax.Array,
                   interpret: Optional[bool] = None) -> jax.Array:
     """Density greedy for P2. values (N,M), costs (N,), budgets (M,),
     eligible (N,M) bool -> assign (N,) int32 (-1 = unselected)."""
-    use_k, interp = resolve_kernel_mode(use_kernel)
+    use_k, _ = resolve_kernel_mode(use_kernel)
     if use_k:
         return budgeted_topk(values, costs, budgets, eligible,
                              use_kernel=True, tile=tile,
-                             interpret=interp if interpret is None
-                             else interpret)
+                             interpret=interpret)
     n, m = values.shape
     density = jnp.where(eligible,
                         values / jnp.maximum(costs[:, None], 1e-12),
@@ -105,12 +104,11 @@ def flgreedy_assign(values: jax.Array, costs: jax.Array, budgets: jax.Array,
                     interpret: Optional[bool] = None) -> jax.Array:
     """Cost-benefit greedy for the monotone submodular P3 (Eq. 19):
     utility(total) = sqrt(total / M). Exact (non-lazy) marginal gains."""
-    use_k, interp = resolve_kernel_mode(use_kernel)
+    use_k, _ = resolve_kernel_mode(use_kernel)
     if use_k:
         return flgreedy_topk(values, costs, budgets, eligible,
                              num_es=num_es, use_kernel=True, tile=tile,
-                             interpret=interp if interpret is None
-                             else interpret)
+                             interpret=interpret)
     n, m = values.shape
     m_div = float(num_es or m)
 

@@ -130,31 +130,35 @@ def fault_draws(seed, t, n: int, m: int) -> FaultDraws:
 
 # -- shard-addressable slices of the dense streams --------------------------
 #
-# ``jax.random.uniform(key, shape)`` hashes the flat counters
-# ``0 .. prod(shape)`` through threefry2x32 (two counters per invocation:
-# ``i`` and ``i + ceil(total/2)``). Because the schedule is counter-based,
+# ``jax.random.uniform(key, shape)`` hashes each element's flat index
+# ``i`` (``0 <= i < prod(shape)``) through threefry2x32 — the
+# partitionable scheme, ``jax_threefry_partitionable``, the default of the
+# installed JAX: the 64-bit counter ``(i >> 32, i & 0xffffffff)`` in, the
+# xor of the two output words out. Because the schedule is counter-based,
 # a client shard can evaluate the hash at exactly *its* flat indices and
 # recover a bitwise-identical slice of the dense draw tensor without ever
-# materializing the full ``(N, ...)`` array. These helpers replicate the
-# (non-partitionable) threefry lowering of ``jax.random`` bit-for-bit;
-# ``tests/test_mesh_select.py`` pins the parity.
+# materializing the full ``(N, ...)`` array. These helpers replicate
+# ``jax.random``'s bits and float conversions bit-for-bit;
+# ``tests/test_mesh_select.py`` pins the parity against ``round_draws``.
 
 def _bits_at(key, flat, total: int):
     """threefry2x32 bits at flat counter positions ``flat`` of a dense
-    ``random_bits(key, 32, total)`` stream (uint32)."""
+    ``random_bits(key, 32, shape)`` stream, ``prod(shape) == total``
+    (uint32)."""
+    if not jax.config.jax_threefry_partitionable:
+        raise NotImplementedError(
+            "shard-sliced draws replicate the partitionable threefry "
+            "stream; jax_threefry_partitionable is off")
+    if total >= 2 ** 31:
+        raise NotImplementedError(
+            f"a dense stream of {total} draws overflows the int32 flat "
+            "counters of the shard slices")
     k1 = lax.convert_element_type(key[0], jnp.uint32)
     k2 = lax.convert_element_type(key[1], jnp.uint32)
-    half = (total + 1) // 2
     f = jnp.asarray(flat, jnp.uint32)
-    lo_half = jnp.asarray(flat) < half
-    # dense stream pairs counter i with i + half (odd totals drop the
-    # final odd counter's second half-word, mirroring threefry_2x32)
-    c2_lo = jnp.where(jnp.asarray(flat) + half < total,
-                      f + np.uint32(half), np.uint32(0))
-    c1 = jnp.where(lo_half, f, f - np.uint32(half))
-    c2 = jnp.where(lo_half, c2_lo, f)
-    o1, o2 = threefry2x32_p.bind(k1, k2, c1.ravel(), c2.ravel())
-    return jnp.where(lo_half, o1.reshape(f.shape), o2.reshape(f.shape))
+    o1, o2 = threefry2x32_p.bind(k1, k2, jnp.zeros_like(f).ravel(),
+                                 f.ravel())
+    return (o1 ^ o2).reshape(f.shape)
 
 
 def uniform_at(key, flat, total: int, lo=0.0, hi=1.0):

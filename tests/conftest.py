@@ -1,22 +1,11 @@
-import importlib.util
-import os
-import sys
-
 import numpy as np
 import pytest
+from hypothesis import settings
 
-# Offline fallback: if the real `hypothesis` package (declared in
-# pyproject's test extra) is not installed, vendor the minimal stub so
-# the property tests still collect and run deterministically.
-try:
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:
-    _stub_path = os.path.join(os.path.dirname(__file__),
-                              "_hypothesis_stub.py")
-    _spec = importlib.util.spec_from_file_location("hypothesis", _stub_path)
-    _mod = importlib.util.module_from_spec(_spec)
-    _spec.loader.exec_module(_mod)
-    sys.modules["hypothesis"] = _mod
+# The first example of a property test includes a jit compile (seconds),
+# so a per-example deadline would time the compiler, not the property.
+settings.register_profile("repro", deadline=None)
+settings.load_profile("repro")
 
 
 @pytest.fixture

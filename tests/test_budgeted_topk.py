@@ -4,8 +4,7 @@ while-loop solvers, oracle optimality bounds, and the bitonic sort core.
 The greedy pick order is a strict total order (density desc, flat index
 desc), so the tile-sorted walk must reproduce ``greedy_assign`` /
 ``flgreedy_assign`` *bitwise* — ties, zero budgets and all-infeasible
-instances included. Property-style tests run under hypothesis (or the
-offline stub in ``tests/_hypothesis_stub.py``)."""
+instances included. Property-style tests run under hypothesis."""
 import jax.numpy as jnp
 import numpy as np
 import pytest

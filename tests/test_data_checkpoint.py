@@ -21,10 +21,13 @@ def test_non_iid_split_label_budget(num_clients, lpc, seed):
     assert len(splits) == num_clients
     all_idx = np.concatenate(splits)
     assert len(np.unique(all_idx)) == len(all_idx), "no sample reuse"
+    # shard-based split: a client holds lpc runs of the label-sorted
+    # samples; a run of L samples covers the tail of one label, at most
+    # (L - 2) // (smallest class) whole labels and the head of another
+    run = -(-len(y) // (num_clients * lpc))
+    per_shard = 2 + (run - 2) // int(np.bincount(y).min())
     for s in splits:
-        # shard-based split: a shard can straddle up to two label
-        # boundaries when class counts are uneven, so at most lpc+2
-        assert len(np.unique(y[s])) <= lpc + 2
+        assert len(np.unique(y[s])) <= lpc * per_shard
 
 
 def test_federated_dataset_shapes():

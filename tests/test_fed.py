@@ -60,6 +60,34 @@ def test_local_sgd_matches_manual():
     np.testing.assert_allclose(np.asarray(delta["w"]), [0.75])
 
 
+def test_cifar_cnn_local_sgd_stays_finite():
+    """Eq. 2 at the non-convex config's own lr, steps and batch on its
+    non-IID synthetic shards: the paper CNN's local SGD stays finite
+    (at lr 0.1 it overflowed within the first round, so every eval of a
+    cifar10-nonconvex run read NaN)."""
+    from repro.api import TrainSpec
+    from repro.configs.paper_hfl import CIFAR10_NONCONVEX as exp
+    from repro.data.federated import FederatedDataset
+    from repro.models.logistic import make_loss_fn, make_model
+
+    spec = TrainSpec(model="cnn")
+    data = FederatedDataset.synthetic(exp.num_clients, kind="cifar", seed=0)
+    params, _ = make_model("cnn", jax.random.PRNGKey(0),
+                           input_shape=(32, 32, 3))
+    loss = make_loss_fn("cnn")
+    sgd = jax.jit(lambda p, b: local_sgd(p, loss, b, exp.lr))
+    steps = exp.local_epochs * spec.batches_per_epoch
+    for c in (0, 1, 3):   # shards that overflowed at lr 0.1
+        cd = data.clients[c]
+        idx = np.random.default_rng(c).integers(0, len(cd.y),
+                                                (steps, spec.batch_size))
+        delta, mean_loss = sgd(params, {"x": jnp.asarray(cd.x[idx]),
+                                        "y": jnp.asarray(cd.y[idx])})
+        assert np.isfinite(float(mean_loss)) and float(mean_loss) < 2.5
+        assert all(np.isfinite(np.asarray(d)).all()
+                   for d in jax.tree.leaves(delta))
+
+
 def test_hfl_simulation_learns():
     import dataclasses as dc
     exp = dc.replace(MNIST_CONVEX, lr=0.05)

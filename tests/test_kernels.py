@@ -89,3 +89,36 @@ def test_rwkv6_scan_vs_ref(b, h, t, dk, dv, chunk):
                                atol=2e-4, rtol=1e-3)
     np.testing.assert_allclose(np.asarray(f1), np.asarray(f2),
                                atol=2e-4, rtol=1e-3)
+
+
+@pytest.mark.parametrize("interpret,want", [(None, True), (True, True),
+                                            (False, False)])
+def test_resolve_interpret_follows_backend(interpret, want):
+    """``interpret=None`` (every kernel wrapper's default) resolves like
+    ``resolve_kernel_mode``: interpreted off-TPU, compiled on a TPU."""
+    from repro.kernels.common import resolve_interpret, resolve_kernel_mode
+    assert jax.default_backend() == "cpu"
+    assert resolve_interpret(interpret) is want
+    assert resolve_kernel_mode(None) == (False, True)
+
+
+def test_fastest_times_kernels_from_inside_a_trace():
+    """The ``best_tile`` autotuners run at trace time: ``fastest`` must
+    execute its Pallas calls (not stage them into the caller's jaxpr)
+    and leave the kernel body's own tracing intact."""
+    from repro.kernels.common import fastest
+    picks = []
+
+    def inputs():
+        return (jnp.zeros((2048,)), jnp.ones((4, 2048)), jnp.ones((4,)))
+
+    def run(tile, *args):
+        return masked_aggregate_kernel(*args, tile=tile, interpret=True)
+
+    def outer(x):
+        picks.append(fastest((256, 512), inputs, run))
+        return x + 1
+
+    jaxpr = jax.make_jaxpr(jax.vmap(outer))(jnp.ones((2, 3)))
+    assert picks[0] in (256, 512)
+    assert "pallas_call" not in str(jaxpr)

@@ -13,6 +13,7 @@ single-device suite skips it. Three pins:
   (the control) is full of (N, M) intermediates.
 """
 import jax
+import jax.extend.core
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -78,9 +79,9 @@ def test_metropolis_100k_end_to_end():
 
 
 def _iter_jaxprs(val):
-    if isinstance(val, jax.core.ClosedJaxpr):
+    if isinstance(val, jax.extend.core.ClosedJaxpr):
         yield val.jaxpr
-    elif isinstance(val, jax.core.Jaxpr):
+    elif isinstance(val, jax.extend.core.Jaxpr):
         yield val
     elif isinstance(val, (tuple, list)):
         for v in val:

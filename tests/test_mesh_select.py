@@ -111,13 +111,14 @@ def test_hier_all_ties():
 
 def test_shard_round_draws_slice_dense_stream():
     """Per-shard draw generation is a bitwise row slice of the dense
-    stream — the property that makes sharded env generation exact."""
+    ``jax.random`` streams (``round_draws``, ``fault_draws``) — the
+    property that makes sharded env generation exact."""
     from repro.sim import draws
     n, m, k_mc = 64, 4, 8
     seed = jnp.uint32(5)
     for t in (0, 7):
-        dense = draws.shard_round_draws(seed, t, n, m, k_mc, 0, n)
-        for shards in (2, 4):
+        dense = draws.round_draws(seed, t, n, m, k_mc)
+        for shards in (1, 2, 4):
             n_local = n // shards
             for s in range(shards):
                 part = draws.shard_round_draws(seed, t, n, m, k_mc,
@@ -130,7 +131,16 @@ def test_shard_round_draws_slice_dense_stream():
                     want = (b[:, lo:lo + n_local]
                             if field.startswith("mc_")
                             else b[lo:lo + n_local])
-                    np.testing.assert_array_equal(a, want)
+                    np.testing.assert_array_equal(a, want, err_msg=field)
+                fpart = draws.shard_fault_draws(seed, t, n, m, lo, n_local)
+                fdense = draws.fault_draws(seed, t, n, m)
+                for field in fpart._fields:
+                    b = np.asarray(getattr(fdense, field))
+                    # out_u is the (M,) ES stream, replicated per shard
+                    want = b if field == "out_u" else b[lo:lo + n_local]
+                    np.testing.assert_array_equal(
+                        np.asarray(getattr(fpart, field)), want,
+                        err_msg=field)
 
 
 # -- ShardSpec serialization -------------------------------------------------
