@@ -52,6 +52,7 @@ from repro.fed.edge import broadcast_global, effective_mask_multi
 from repro.fed.robust import robust_aggregate_stacked
 from repro.experiment.packing import pack_assignment
 from repro.models.logistic import accuracy, softmax_xent
+from repro.obs import scopes
 from repro.obs.telemetry import acc_init, acc_update, round_frame
 from repro.policies.base import FunctionalPolicy
 
@@ -109,57 +110,61 @@ def _train_round_step(policy: FunctionalPolicy, spec: BatchedRoundSpec,
     def step(pstate, edge, rd, stacked_x, stacked_y, stacked_sizes,
              base_keys, budgets=None, env_seeds=None):
         n_seeds = base_keys.shape[0]
-        assign, aux = _select(pstate, rd, budgets)
-        new_pstate = jax.vmap(policy.update)(pstate, rd, assign, aux)
-        ci, valid, arrived, tau = jax.vmap(
-            pack_assignment, in_axes=(0, 0, 0, None, None))(
-                assign, rd.outcomes, rd.latency, m, slots)
-        idx = jax.vmap(device_batch_indices,
-                       in_axes=(0, 0, 0, None, None, None))(
-            base_keys, rd.t, ci, stacked_sizes, steps, batch)
-        xb = stacked_x[ci[..., None, None], idx]  # (S,M,slots,steps,B,..)
-        yb = stacked_y[ci[..., None, None], idx]
-        flat = n_seeds * m * slots
-        batches = {
-            "x": xb.reshape((flat, steps, batch) + xb.shape[5:]),
-            "y": yb.reshape(flat, steps, batch),
-        }
-        slot_params = jax.tree.map(
-            lambda a: jnp.broadcast_to(
-                a[:, :, None], (n_seeds, m, slots) + a.shape[2:]
-            ).reshape((flat,) + a.shape[2:]), edge)
-        deltas = slot_train(slot_params, batches,
-                            valid.reshape(flat) > 0, spec, loss_fn)
-        deltas = jax.tree.map(
-            lambda d: d.reshape((n_seeds, m, slots) + d.shape[1:]),
-            deltas)
-        slot_c = None
-        if corrupting:
-            from repro.sim import draws
-            from repro.sim.faults import corrupt_mask
-            n_clients = rd.eligible.shape[1]
-            corr_u = jax.vmap(lambda se: draws.fault_draws(
-                se, rd.t[0], n_clients, m).corr_u)(env_seeds)   # (S, N)
-            cmask = corrupt_mask(faults, corr_u, jnp)
-            slot_c = jax.vmap(lambda cm, idx: cm[idx])(cmask, ci)
-            scale = jnp.where(slot_c, jnp.float32(faults.corrupt_scale),
-                              jnp.float32(1.0))                 # (S,M,slots)
+        with jax.named_scope(scopes.SELECT):
+            assign, aux = _select(pstate, rd, budgets)
+            new_pstate = jax.vmap(policy.update)(pstate, rd, assign, aux)
+        with jax.named_scope(scopes.TRAIN):
+            ci, valid, arrived, tau = jax.vmap(
+                pack_assignment, in_axes=(0, 0, 0, None, None))(
+                    assign, rd.outcomes, rd.latency, m, slots)
+            idx = jax.vmap(device_batch_indices,
+                           in_axes=(0, 0, 0, None, None, None))(
+                base_keys, rd.t, ci, stacked_sizes, steps, batch)
+            xb = stacked_x[ci[..., None, None], idx]  # (S,M,slots,steps,B,..)
+            yb = stacked_y[ci[..., None, None], idx]
+            flat = n_seeds * m * slots
+            batches = {
+                "x": xb.reshape((flat, steps, batch) + xb.shape[5:]),
+                "y": yb.reshape(flat, steps, batch),
+            }
+            slot_params = jax.tree.map(
+                lambda a: jnp.broadcast_to(
+                    a[:, :, None], (n_seeds, m, slots) + a.shape[2:]
+                ).reshape((flat,) + a.shape[2:]), edge)
+            deltas = slot_train(slot_params, batches,
+                                valid.reshape(flat) > 0, spec, loss_fn)
             deltas = jax.tree.map(
-                lambda d: d * scale.reshape(
-                    scale.shape + (1,) * (d.ndim - 3)), deltas)
-        w = effective_mask_multi(
-            arrived.reshape(n_seeds * m, slots),
-            tau.reshape(n_seeds * m, slots),
-            valid.reshape(n_seeds * m, slots),
-            spec.z_min).reshape(n_seeds, m, slots)
-        new_edge = robust_aggregate_stacked(
-            edge, deltas, w, aggregator=spec.aggregator,
-            trim_frac=spec.trim_frac, use_kernel=spec.use_kernel,
-            tile=spec.tile, interpret=spec.interpret)
-        sync = ((rd.t[0] + 1) % spec.t_es) == 0
-        synced = jax.vmap(broadcast_global)(new_edge)
-        new_edge = jax.tree.map(
-            lambda a, c: jnp.where(sync, a, c), synced, new_edge)
+                lambda d: d.reshape((n_seeds, m, slots) + d.shape[1:]),
+                deltas)
+        with jax.named_scope(scopes.AGGREGATE):
+            slot_c = None
+            if corrupting:
+                from repro.sim import draws
+                from repro.sim.faults import corrupt_mask
+                n_clients = rd.eligible.shape[1]
+                corr_u = jax.vmap(lambda se: draws.fault_draws(
+                    se, rd.t[0], n_clients, m).corr_u)(env_seeds)   # (S, N)
+                cmask = corrupt_mask(faults, corr_u, jnp)
+                slot_c = jax.vmap(lambda cm, idx: cm[idx])(cmask, ci)
+                scale = jnp.where(slot_c,
+                                  jnp.float32(faults.corrupt_scale),
+                                  jnp.float32(1.0))         # (S,M,slots)
+                deltas = jax.tree.map(
+                    lambda d: d * scale.reshape(
+                        scale.shape + (1,) * (d.ndim - 3)), deltas)
+            w = effective_mask_multi(
+                arrived.reshape(n_seeds * m, slots),
+                tau.reshape(n_seeds * m, slots),
+                valid.reshape(n_seeds * m, slots),
+                spec.z_min).reshape(n_seeds, m, slots)
+            new_edge = robust_aggregate_stacked(
+                edge, deltas, w, aggregator=spec.aggregator,
+                trim_frac=spec.trim_frac, use_kernel=spec.use_kernel,
+                tile=spec.tile, interpret=spec.interpret)
+            sync = ((rd.t[0] + 1) % spec.t_es) == 0
+            synced = jax.vmap(broadcast_global)(new_edge)
+            new_edge = jax.tree.map(
+                lambda a, c: jnp.where(sync, a, c), synced, new_edge)
         parts = jnp.sum(arrived * valid, axis=(1, 2))     # (S,)
         util = jnp.sqrt(parts / m) if sqrt_u else parts
         explored = (aux.get("explored",
@@ -178,10 +183,11 @@ def _train_round_step(policy: FunctionalPolicy, spec: BatchedRoundSpec,
 
 def _block_eval(logits_fn, edge, test_x, test_y):
     """Batched eval: global model per seed = mean over its M edge models."""
-    global_params = jax.tree.map(lambda a: jnp.mean(a, axis=1), edge)
-    logits = jax.vmap(lambda p: logits_fn(p, test_x))(global_params)
-    acc = jax.vmap(accuracy, in_axes=(0, None))(logits, test_y)
-    loss = jax.vmap(softmax_xent, in_axes=(0, None))(logits, test_y)
+    with jax.named_scope(scopes.EVAL):
+        global_params = jax.tree.map(lambda a: jnp.mean(a, axis=1), edge)
+        logits = jax.vmap(lambda p: logits_fn(p, test_x))(global_params)
+        acc = jax.vmap(accuracy, in_axes=(0, None))(logits, test_y)
+        loss = jax.vmap(softmax_xent, in_axes=(0, None))(logits, test_y)
     return acc, loss
 
 

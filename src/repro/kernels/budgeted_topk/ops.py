@@ -35,6 +35,7 @@ from repro.kernels.budgeted_topk.kernel import density_sort_kernel
 from repro.kernels.budgeted_topk.ref import (pair_density,
                                              sorted_candidates_ref)
 from repro.kernels.common import fastest
+from repro.obs import scopes
 
 DEFAULT_TILE = 128
 
@@ -230,7 +231,8 @@ def greedy_walk(segs: Segments, budgets: jax.Array, *, num_es: int,
 
     carry = (jnp.full(n_loc, -1, jnp.int32), budgets.astype(dtype),
              jnp.zeros((), jnp.int32), jnp.ones((), bool))
-    assign, remaining, _, _ = lax.while_loop(cond, body, carry)
+    with jax.named_scope(scopes.WALK):
+        assign, remaining, _, _ = lax.while_loop(cond, body, carry)
     return assign, remaining
 
 
@@ -283,7 +285,8 @@ def flgreedy_walk(segs: Segments, budgets: jax.Array, *, num_es: int,
     carry = (jnp.full(n_loc, -1, jnp.int32), budgets.astype(dtype),
              jnp.zeros((), dtype), jnp.zeros((), jnp.int32),
              jnp.ones((), bool))
-    assign, remaining, _, _, _ = lax.while_loop(cond, body, carry)
+    with jax.named_scope(scopes.WALK):
+        assign, remaining, _, _, _ = lax.while_loop(cond, body, carry)
     return assign, remaining
 
 

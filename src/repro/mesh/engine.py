@@ -46,6 +46,7 @@ from repro.fed.robust import robust_aggregate_stacked
 from repro.kernels.common import resolve_kernel_mode
 from repro.mesh.select import shard_assign
 from repro.mesh.topology import cohort_mesh
+from repro.obs import scopes
 from repro.obs.telemetry import (TelemetryFrame, acc_init, acc_update,
                                  aggregator_adjusted)
 from repro.policies.base import FunctionalPolicy
@@ -208,58 +209,61 @@ def sharded_block_device(policy: FunctionalPolicy, spec: BatchedRoundSpec,
                 lambda se, st, p: gen_round(se, st, p, t))(seeds, statics,
                                                            pos)
             rd = sr.round
-            assign, under = jax.vmap(select)(pstate, rd)
-            # the dense step's per-seed aux {"explored": under.any()},
-            # OR-reduced over the mesh (the same global any)
-            explored = lax.psum(
-                under.any(axis=(1, 2)).astype(jnp.int32), "clients") > 0
-            new_pstate = jax.vmap(policy.update)(pstate, rd, assign,
-                                                 {"explored": explored})
-            ci, valid, arrived, tau = jax.vmap(
-                lambda a, o, l: pack_assignment_sharded(
-                    a, o, l, m, slots, "clients", lo))(
-                        assign, rd.outcomes, rd.latency)
-            idx = jax.vmap(device_batch_indices,
-                           in_axes=(0, 0, 0, None, None, None))(
-                base_keys, rd.t, ci, stacked_sizes, steps, batch)
-            # client-sharded data: each shard gathers the slots whose
-            # client rows it owns and a psum assembles the replicated
-            # slot batches — exactly one contributor per realized slot;
-            # padding slots are client 0, owned by shard 0, the same
-            # rows the dense gather pulls for them
-            owns = (ci >= lo) & (ci < lo + n_local)
-            cl = jnp.clip(ci - lo, 0, n_local - 1)
-            xb = lax.psum(_own(stacked_x[cl[..., None, None], idx],
-                               owns[..., None, None]), "clients")
-            yb = lax.psum(_own(stacked_y[cl[..., None, None], idx],
-                               owns[..., None, None]), "clients")
-            flat = n_seeds * m * slots
-            batches = {
-                "x": xb.reshape((flat, steps, batch) + xb.shape[5:]),
-                "y": yb.reshape(flat, steps, batch),
-            }
-            slot_params = jax.tree.map(
-                lambda a: jnp.broadcast_to(
-                    a[:, :, None], (n_seeds, m, slots) + a.shape[2:]
-                ).reshape((flat,) + a.shape[2:]), edge)
-            deltas = slot_train(slot_params, batches,
-                                valid.reshape(flat) > 0, spec, loss_fn)
-            deltas = jax.tree.map(
-                lambda d: d.reshape((n_seeds, m, slots) + d.shape[1:]),
-                deltas)
-            w = _mask_topz(
-                arrived.reshape(n_seeds * m, slots),
-                tau.reshape(n_seeds * m, slots),
-                valid.reshape(n_seeds * m, slots),
-                spec.z_min).reshape(n_seeds, m, slots)
-            new_edge = robust_aggregate_stacked(
-                edge, deltas, w, aggregator=spec.aggregator,
-                trim_frac=spec.trim_frac, use_kernel=spec.use_kernel,
-                tile=spec.tile, interpret=spec.interpret)
-            sync = ((rd.t[0] + 1) % spec.t_es) == 0
-            synced = jax.vmap(broadcast_global)(new_edge)
-            new_edge = jax.tree.map(
-                lambda a, c: jnp.where(sync, a, c), synced, new_edge)
+            with jax.named_scope(scopes.SELECT):
+                assign, under = jax.vmap(select)(pstate, rd)
+                # the dense step's per-seed aux {"explored": under.any()},
+                # OR-reduced over the mesh (the same global any)
+                explored = lax.psum(
+                    under.any(axis=(1, 2)).astype(jnp.int32), "clients") > 0
+                new_pstate = jax.vmap(policy.update)(pstate, rd, assign,
+                                                     {"explored": explored})
+            with jax.named_scope(scopes.TRAIN):
+                ci, valid, arrived, tau = jax.vmap(
+                    lambda a, o, l: pack_assignment_sharded(
+                        a, o, l, m, slots, "clients", lo))(
+                            assign, rd.outcomes, rd.latency)
+                idx = jax.vmap(device_batch_indices,
+                               in_axes=(0, 0, 0, None, None, None))(
+                    base_keys, rd.t, ci, stacked_sizes, steps, batch)
+                # client-sharded data: each shard gathers the slots whose
+                # client rows it owns and a psum assembles the replicated
+                # slot batches — exactly one contributor per realized slot;
+                # padding slots are client 0, owned by shard 0, the same
+                # rows the dense gather pulls for them
+                owns = (ci >= lo) & (ci < lo + n_local)
+                cl = jnp.clip(ci - lo, 0, n_local - 1)
+                xb = lax.psum(_own(stacked_x[cl[..., None, None], idx],
+                                   owns[..., None, None]), "clients")
+                yb = lax.psum(_own(stacked_y[cl[..., None, None], idx],
+                                   owns[..., None, None]), "clients")
+                flat = n_seeds * m * slots
+                batches = {
+                    "x": xb.reshape((flat, steps, batch) + xb.shape[5:]),
+                    "y": yb.reshape(flat, steps, batch),
+                }
+                slot_params = jax.tree.map(
+                    lambda a: jnp.broadcast_to(
+                        a[:, :, None], (n_seeds, m, slots) + a.shape[2:]
+                    ).reshape((flat,) + a.shape[2:]), edge)
+                deltas = slot_train(slot_params, batches,
+                                    valid.reshape(flat) > 0, spec, loss_fn)
+                deltas = jax.tree.map(
+                    lambda d: d.reshape((n_seeds, m, slots) + d.shape[1:]),
+                    deltas)
+            with jax.named_scope(scopes.AGGREGATE):
+                w = _mask_topz(
+                    arrived.reshape(n_seeds * m, slots),
+                    tau.reshape(n_seeds * m, slots),
+                    valid.reshape(n_seeds * m, slots),
+                    spec.z_min).reshape(n_seeds, m, slots)
+                new_edge = robust_aggregate_stacked(
+                    edge, deltas, w, aggregator=spec.aggregator,
+                    trim_frac=spec.trim_frac, use_kernel=spec.use_kernel,
+                    tile=spec.tile, interpret=spec.interpret)
+                sync = ((rd.t[0] + 1) % spec.t_es) == 0
+                synced = jax.vmap(broadcast_global)(new_edge)
+                new_edge = jax.tree.map(
+                    lambda a, c: jnp.where(sync, a, c), synced, new_edge)
             parts = jnp.sum(arrived * valid, axis=(1, 2))     # (S,)
             util = jnp.sqrt(parts / m) if sqrt_u else parts
             outs = (assign, util, parts, explored)

@@ -4,7 +4,11 @@ Three coordinated layers (see ROADMAP "Observability"):
 
 * host tracing   — ``obs.span``/``obs.event``/``obs.trace_to`` write a
                    JSONL event log of the run lifecycle (+ Perfetto
-                   export, + opt-in ``jax.profiler`` capture);
+                   export, + opt-in ``jax.profiler`` capture); under a
+                   running ``jax.profiler`` trace each span is also a
+                   ``repro:<name>`` annotation on the profiler's clock,
+                   and ``repro.obs.scopes`` names the ``hfl.*`` stage
+                   scopes the compiled rounds carry on the device;
 * device taps    — ``ObsSpec(telemetry=True)`` threads a pure metric
                    accumulator through the tier-3/4 fused scan and
                    surfaces it as ``RunResult.telemetry``;

@@ -1,18 +1,29 @@
 """Host span tracer: a JSONL event log for the run lifecycle.
 
-The tracer is deliberately tiny — no external deps, one file handle, a
-thread lock — because it sits on the hot dispatch path of the fused
-engine. Each record is one JSON object per line:
+The tracer is deliberately tiny — no external deps, records kept in
+memory — because it sits on the hot dispatch path of the fused engine.
+Each record is one JSON object per line:
 
     {"ev": "span",  "name": "fused_block", "ts": ..., "dur_us": ...,
-     "pid": ..., "tid": ..., ...attrs}
+     "pid": ..., "tid": ..., "id": ..., "parent": ..., "run": ...,
+     ...attrs}
     {"ev": "event", "name": "health",      "ts": ..., ...attrs}
 
 ``ts`` is microseconds from ``time.perf_counter_ns`` (monotonic; only
 deltas within one log are meaningful), plus a ``wall`` ISO timestamp on
-the header record for humans. ``export_perfetto`` renders the log as a
-Chrome ``trace_event`` JSON that chrome://tracing and ui.perfetto.dev
-load directly.
+the header record for humans. ``id`` numbers the span, ``parent`` is the
+id of the span it ran inside (null at the top), and ``run`` is shared by
+every span and event of one ``repro.run`` call (null outside one). The
+records are written when the tracer closes (``close``, the end of
+``trace_to``, or process exit), not one write per record.
+``export_perfetto`` renders the log as a Chrome ``trace_event`` JSON that
+chrome://tracing and ui.perfetto.dev load directly.
+
+While a ``jax.profiler`` trace is running, every span also opens a
+``jax.profiler.TraceAnnotation`` named ``repro:<name>`` carrying the same
+three ids, so the span sits on the profiler's host plane, on the clock
+of the device's op events. This happens whether or not a JSONL tracer is
+active, and only once ``jax`` is imported: the tracer never imports it.
 
 Activation is explicit (``configure``/``trace_to``/``run_tracing``) or
 via environment for zero-code capture of existing entry points:
@@ -21,20 +32,32 @@ via environment for zero-code capture of existing entry points:
         python benchmarks/run.py ...
 
 Instrumentation sites call ``span``/``event`` unconditionally; when no
-tracer is active they cost one attribute check and no allocation.
+tracer is active and no profiler runs they cost two flag checks and no
+allocation.
 """
 from __future__ import annotations
 
 import atexit
 import contextlib
+import contextvars
 import datetime
+import itertools
 import json
 import os
+import sys
 import threading
 import time
-from typing import Any, Dict, Iterator, Optional
+from typing import Any, Dict, Iterator, List, Optional
 
 _SCHEMA = "repro-trace/v1"
+ANNOTATION_PREFIX = "repro:"
+
+_IDS = itertools.count(1)
+# the id of the innermost open span, and of the repro.run call
+_PARENT: contextvars.ContextVar[Optional[int]] = contextvars.ContextVar(
+    "repro_obs_parent", default=None)
+_RUN: contextvars.ContextVar[Optional[int]] = contextvars.ContextVar(
+    "repro_obs_run", default=None)
 
 
 def now_us() -> int:
@@ -45,43 +68,57 @@ def now_us() -> int:
 _now_us = now_us
 
 
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation`` while a profiler trace runs;
+    None when none runs or ``jax`` was never imported."""
+    ann = getattr(sys.modules.get("jax.profiler"), "TraceAnnotation", None)
+    return ann if ann is not None and ann.is_enabled() else None
+
+
 class Tracer:
-    """Appends span/event records to a JSONL file, thread-safely."""
+    """Keeps span/event records in memory and appends them to a JSONL
+    file on ``close`` (also called at process exit)."""
 
     def __init__(self, path: str, perfetto: Optional[str] = None):
         self.path = path
         self.perfetto = perfetto
         d = os.path.dirname(os.path.abspath(path))
         os.makedirs(d, exist_ok=True)
-        self._f = open(path, "a", encoding="utf-8")
+        self._lines: List[str] = []
+        self._closed = False
         self._lock = threading.Lock()
         self._pid = os.getpid()
-        self._write({"ev": "begin", "name": _SCHEMA, "ts": _now_us(),
-                     "wall": datetime.datetime.now(datetime.timezone.utc)
-                     .isoformat()})
+        self._add({"ev": "begin", "name": _SCHEMA, "ts": _now_us(),
+                   "wall": datetime.datetime.now(datetime.timezone.utc)
+                   .isoformat()})
+        atexit.register(self.close)
 
-    def _write(self, rec: Dict[str, Any]) -> None:
-        line = json.dumps(rec, default=_jsonable)
-        with self._lock:
-            self._f.write(line + "\n")
-            self._f.flush()
+    def _add(self, rec: Dict[str, Any]) -> None:
+        # list.append is atomic: no lock and no I/O on the dispatch path
+        self._lines.append(json.dumps(rec, default=_jsonable))
 
     def event(self, name: str, **attrs: Any) -> None:
-        self._write({"ev": "event", "name": name, "ts": _now_us(),
-                     "pid": self._pid,
-                     "tid": threading.get_ident() & 0xFFFF, **attrs})
+        self._add({"ev": "event", "name": name, "ts": _now_us(),
+                   "pid": self._pid, "tid": threading.get_ident() & 0xFFFF,
+                   "parent": _PARENT.get(), "run": _RUN.get(), **attrs})
 
     def span_record(self, name: str, ts: int, dur_us: int,
-                    attrs: Dict[str, Any]) -> None:
-        self._write({"ev": "span", "name": name, "ts": ts,
-                     "dur_us": dur_us, "pid": self._pid,
-                     "tid": threading.get_ident() & 0xFFFF, **attrs})
+                    attrs: Dict[str, Any],
+                    ids: Optional[Dict[str, Any]] = None) -> None:
+        self._add({"ev": "span", "name": name, "ts": ts,
+                   "dur_us": dur_us, "pid": self._pid,
+                   "tid": threading.get_ident() & 0xFFFF,
+                   **(ids or {}), **attrs})
 
     def close(self) -> None:
         with self._lock:
-            if self._f.closed:
+            if self._closed:
                 return
-            self._f.close()
+            self._closed = True
+            lines, self._lines = self._lines, []
+        atexit.unregister(self.close)
+        with open(self.path, "a", encoding="utf-8") as f:
+            f.write("".join(line + "\n" for line in lines))
         if self.perfetto:
             export_perfetto(self.path, self.perfetto)
 
@@ -111,7 +148,6 @@ def active() -> Optional[Tracer]:
         if path:
             _TRACER = Tracer(path,
                              os.environ.get("REPRO_TRACE_PERFETTO") or None)
-            atexit.register(_close_global)
     return _TRACER
 
 
@@ -150,16 +186,27 @@ def trace_to(path: str, perfetto: Optional[str] = None) -> Iterator[Tracer]:
 def span(name: str, **attrs: Any) -> Iterator[Dict[str, Any]]:
     """Time the enclosed block. Yields the attrs dict so the body can
     attach results (e.g. compile hit/miss) before the record is
-    written. No-op (and no allocation beyond the dict) when inactive."""
+    written. While a ``jax.profiler`` trace runs, the block is also a
+    ``repro:<name>`` annotation on the profiler's host plane. No-op (and
+    no allocation beyond the dict) when neither is on."""
     tr = active()
-    if tr is None:
+    ann = _profiler_annotation()
+    if tr is None and ann is None:
         yield attrs
         return
+    ids = {"id": next(_IDS), "parent": _PARENT.get(), "run": _RUN.get()}
+    token = _PARENT.set(ids["id"])
+    mark = (ann(ANNOTATION_PREFIX + name,
+                **{k: v for k, v in ids.items() if v is not None})
+            if ann is not None else contextlib.nullcontext())
     t0 = _now_us()
     try:
-        yield attrs
+        with mark:
+            yield attrs
     finally:
-        tr.span_record(name, t0, _now_us() - t0, attrs)
+        _PARENT.reset(token)
+        if tr is not None:
+            tr.span_record(name, t0, _now_us() - t0, attrs, ids)
 
 
 def event(name: str, **attrs: Any) -> None:
@@ -172,13 +219,16 @@ def event(name: str, **attrs: Any) -> None:
 @contextlib.contextmanager
 def run_tracing(obs_spec) -> Iterator[None]:
     """Scope a run's tracing to its ObsSpec: JSONL trace, optional
-    Perfetto export on close, optional jax.profiler capture."""
+    Perfetto export on close, optional jax.profiler capture. Every span
+    and event inside carries one fresh ``run`` id."""
+    run_token = _RUN.set(next(_IDS))
     prof = None
-    if getattr(obs_spec, "jax_profiler", None):
-        import jax
-        prof = jax.profiler.trace(obs_spec.jax_profiler)
-        prof.__enter__()
     try:
+        if getattr(obs_spec, "jax_profiler", None):
+            import jax
+            capture = jax.profiler.trace(obs_spec.jax_profiler)
+            capture.__enter__()
+            prof = capture
         if getattr(obs_spec, "trace", None):
             with trace_to(obs_spec.trace, obs_spec.perfetto):
                 yield
@@ -187,6 +237,7 @@ def run_tracing(obs_spec) -> Iterator[None]:
     finally:
         if prof is not None:
             prof.__exit__(None, None, None)
+        _RUN.reset(run_token)
 
 
 # -- Perfetto / Chrome trace_event export -------------------------------------

@@ -25,6 +25,7 @@ import numpy as np
 
 from repro.core.network import RoundData
 from repro.core.utility import realized_utility
+from repro.obs import scopes
 from repro.policies.base import (FunctionalPolicy, PolicyAdapter, Round,
                                  stack_rounds)
 
@@ -63,11 +64,13 @@ def policy_scan_step(policy: FunctionalPolicy, budgets=None):
     baked-in ``spec.budgets()``."""
 
     def step(state, rd: Round):
-        if budgets is None:
-            assign, aux = policy.select(state, rd)
-        else:
-            assign, aux = policy.select_with_budgets(state, rd, budgets)
-        new_state = policy.update(state, rd, assign, aux)
+        with jax.named_scope(scopes.SELECT):
+            if budgets is None:
+                assign, aux = policy.select(state, rd)
+            else:
+                assign, aux = policy.select_with_budgets(state, rd,
+                                                         budgets)
+            new_state = policy.update(state, rd, assign, aux)
         util, part = traced_utility(assign, rd.outcomes,
                                     policy.spec.num_edge_servers,
                                     policy.spec.sqrt_utility)

@@ -37,6 +37,7 @@ from jax import lax
 
 from repro.kernels.budgeted_topk.ops import budgeted_topk, flgreedy_topk
 from repro.kernels.common import resolve_kernel_mode
+from repro.obs import scopes
 
 
 def feasible_cohort_bound(budget: float, min_cost: float,
@@ -92,7 +93,8 @@ def greedy_assign(values: jax.Array, costs: jax.Array, budgets: jax.Array,
     assign0 = jnp.full(n, -1, jnp.int32)
     carry = (assign0, budgets.astype(values.dtype), jnp.zeros((), jnp.int32),
              jnp.ones((), bool))
-    assign, _, _, _ = lax.while_loop(cond, body, carry)
+    with jax.named_scope(scopes.WALK):
+        assign, _, _, _ = lax.while_loop(cond, body, carry)
     return assign
 
 
@@ -139,7 +141,8 @@ def flgreedy_assign(values: jax.Array, costs: jax.Array, budgets: jax.Array,
     carry = (assign0, budgets.astype(values.dtype),
              jnp.zeros((), values.dtype), jnp.zeros((), jnp.int32),
              jnp.ones((), bool))
-    assign, _, _, _, _ = lax.while_loop(cond, body, carry)
+    with jax.named_scope(scopes.WALK):
+        assign, _, _, _, _ = lax.while_loop(cond, body, carry)
     return assign
 
 

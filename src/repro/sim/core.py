@@ -37,6 +37,7 @@ from repro.envs.scenarios import ScenarioSpec
 from repro.kernels.common import resolve_kernel_mode
 from repro.kernels.context_pairwise.ops import pairwise_context
 from repro.kernels.context_pairwise.ref import latency, shannon_rate
+from repro.obs import scopes
 from repro.policies.base import Round
 from repro.sim import draws
 from repro.sim.spec import SimSpec
@@ -65,6 +66,7 @@ def _es_pos(spec: SimSpec) -> jnp.ndarray:
     return jnp.asarray(es_positions(spec.num_edge_servers), jnp.float32)
 
 
+@jax.named_scope(scopes.ENV)
 def init_statics(spec: SimSpec, seed) -> SimStatics:
     """Device twin of ``HFLNetworkSim.__init__``/``ScenarioSim.__init__``
     (same draws, float32 math)."""
@@ -112,6 +114,7 @@ def _latency(spec: SimSpec, bandwidth, compute, fad_dt, fad_ut, g0):
                    update_bits=spec.update_bits, workload=spec.workload)
 
 
+@jax.named_scope(scopes.ENV)
 def sim_round(spec: SimSpec, seed, statics: SimStatics, pos, t,
               dr: Optional[draws.RoundDraws] = None,
               fd: Optional[draws.FaultDraws] = None,
@@ -198,6 +201,7 @@ def sim_round(spec: SimSpec, seed, statics: SimStatics, pos, t,
     return pos, SimRound(round=rd, compute=compute, bandwidth=bandwidth)
 
 
+@jax.named_scope(scopes.ENV)
 def round_batch(spec: SimSpec, seeds, statics: SimStatics, pos, t
                 ) -> Tuple[jax.Array, Round]:
     """Seed-batched round generation for fused scans: ``seeds``/``statics``

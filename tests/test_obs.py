@@ -314,3 +314,206 @@ def test_env_var_zero_code_capture(tmp_path, monkeypatch):
     assert [r["name"] for r in recs] == ["repro-trace/v1", "unit", "mark"]
     assert recs[1]["k"] == 1 and recs[2]["n"] == 2
     assert os.path.getsize(trace) > 0
+
+
+# -- stage scopes on the device ------------------------------------------------
+
+STAGES = {"env", "select", "walk", "train", "aggregate", "eval"}
+
+
+def _scoped_ops(jitted, *args):
+    """The ``hfl.*`` scopes the lowered module's debug locations name, and
+    the ``op_name`` of every op of the compiled program: the path a device
+    profile reads for each op event."""
+    import re
+    lowered = jitted.lower(*args)
+    named = set(re.findall(r"hfl\.(\w+)", lowered.as_text(debug_info=True)))
+    ops = re.findall(r'op_name="([^"]*)"', lowered.compile().as_text())
+    return named, ops
+
+
+def _assert_walk_in_select(ops):
+    """Every while loop under ``hfl.walk`` sits inside ``hfl.select``
+    (ops of the program itself, with their full path; reducer bodies
+    keep relative names)."""
+    import re
+    whiles = [o for o in ops if o.startswith("jit(")
+              and re.search(r"hfl\.walk\)*/while$", o)]
+    assert whiles, "no while loop under hfl.walk"
+    for o in whiles:
+        assert re.search(r"hfl\.select\b.*hfl\.walk", o), o
+
+
+def _captured_block(monkeypatch, env):
+    """The tier-4 fused block and its arguments, as ``repro.run`` makes
+    them at a tiny size (the block itself is not run)."""
+    from repro.experiment import sweep
+    got = []
+
+    class Captured(Exception):
+        pass
+
+    def capture(factory, make_args, *rest):
+        got.append(make_args())
+        raise Captured
+
+    monkeypatch.setattr(sweep, "_traced_block", capture)
+    spec = ExperimentSpec(env=env, policy=PolicySpec("cocs"),
+                          train=TrainSpec(model="logreg", batch_size=16),
+                          eval=EvalSpec(eval_every=2), horizon=2,
+                          seeds=SEEDS)
+    with pytest.raises(Captured):
+        repro.run(spec)
+    return got[0]
+
+
+TINY_METRO = {"num_clients": 64, "num_edge_servers": 4, "h_t": 3}
+
+
+@pytest.mark.parametrize("env", [
+    EnvSpec("paper", backend="device"),
+    EnvSpec("metropolis-1k", config="mnist-metropolis-1k",
+            overrides=TINY_METRO, true_p="analytic")],
+    ids=["paper", "metropolis"])
+def test_stage_scopes_cover_the_fused_block(monkeypatch, env):
+    fn, args = _captured_block(monkeypatch, env)
+    named, ops = _scoped_ops(fn, *args)
+    assert STAGES <= named
+    _assert_walk_in_select(ops)
+    for stage in STAGES:
+        assert any(f"hfl.{stage}" in o for o in ops), stage
+
+
+def test_stage_scopes_cover_the_bandit_scan():
+    """Tier 1 (and the training tiers' pre-scan): env and selection only."""
+    import jax.numpy as jnp
+    from repro.policies.engine import stack_states
+    from repro.sim.engine import _compiled_bandit
+    spec = ExperimentSpec(env=EnvSpec("paper", backend="device"),
+                          policy=PolicySpec("cocs"), horizon=3, seeds=SEEDS)
+    env = build_env(spec.env)
+    pol = build_policy(spec.policy, env.cfg, spec.horizon)
+    fn = _compiled_bandit(pol, env.spec, spec.horizon)
+    named, ops = _scoped_ops(fn, jnp.asarray(np.array(SEEDS, np.uint32)),
+                             stack_states(pol, list(SEEDS)))
+    assert named == {"env", "select", "walk"}
+    _assert_walk_in_select(ops)
+
+
+def test_stage_scopes_cover_the_sharded_block():
+    import jax.numpy as jnp
+    from repro import api
+    from repro.experiment.sweep import prepare_training
+    from repro.mesh.engine import ShardDims, sharded_block_device
+    from repro.policies.engine import stack_states
+    from repro.sim.core import init_statics_multi
+    spec = ExperimentSpec(
+        env=EnvSpec("metropolis-1k", config="mnist-metropolis-1k",
+                    overrides=TINY_METRO, true_p="analytic"),
+        policy=PolicySpec("cocs"), train=TrainSpec(batch_size=16),
+        eval=EvalSpec(eval_every=2), horizon=2, seeds=SEEDS,
+        shard=api.ShardSpec(clients=1))
+    env = build_env(spec.env)
+    pol = build_policy(spec.policy, env.cfg, spec.horizon)
+    setup = prepare_training(env.cfg, "logreg", 16, 2, None, list(SEEDS))
+    statics = init_statics_multi(env.spec, list(SEEDS))
+    n = env.cfg.num_clients
+    fn = sharded_block_device(
+        pol, setup.spec, 6, setup.batch, setup.loss_fn, setup.logits_fn,
+        env.spec, ShardDims(num_clients=n, n_local=n, seed_shards=1,
+                            client_shards=1))
+    named, ops = _scoped_ops(
+        fn, setup.stacked.x, setup.stacked.y, setup.stacked.sizes,
+        setup.base_keys, stack_states(pol, list(SEEDS)), setup.edge_seed,
+        statics.pos0, jnp.asarray(np.array(SEEDS, np.uint32)), statics,
+        jnp.arange(0, 2, dtype=jnp.int32), setup.test_x, setup.test_y)
+    assert STAGES <= named
+    _assert_walk_in_select(ops)
+
+
+@pytest.mark.parametrize("solver", ["greedy_assign", "flgreedy_assign",
+                                    "budgeted_topk", "flgreedy_topk"])
+def test_every_budget_walk_is_scoped(solver):
+    """All three walks: the solvers' fallback loops and the sorted
+    ``greedy_walk`` / ``flgreedy_walk`` behind the kernel path."""
+    import jax.numpy as jnp
+    from repro.kernels.budgeted_topk import ops as topk
+    from repro.policies import solvers
+    fn = getattr(solvers, solver, None) or getattr(topk, solver)
+    rng = np.random.default_rng(0)
+    n, m = 12, 3
+    args = (jnp.asarray(rng.random((n, m)), jnp.float32),
+            jnp.asarray(rng.random(n) + 0.5, jnp.float32),
+            jnp.full((m,), 2.0, jnp.float32),
+            jnp.asarray(rng.random((n, m)) > 0.3))
+    named, ops = _scoped_ops(fn, *args)
+    assert named == {"walk"}
+    assert any(o.endswith("hfl.walk/while") for o in ops)
+
+
+# -- spans on the profiler's clock, ids, records kept until close -------------
+
+
+def _host_annotations(prof_dir):
+    import glob
+    from jax.profiler import ProfileData
+    path = glob.glob(os.path.join(prof_dir, "**", "*.xplane.pb"),
+                     recursive=True)[0]
+    return {e.name: e for p in ProfileData.from_file(path).planes
+            if p.name == "/host:CPU" for line in p.lines
+            for e in line.events if e.name.startswith("repro:")}
+
+
+def test_spans_sit_on_the_profiler_clock_with_ids(tmp_path):
+    import jax
+    from repro.obs import trace as tr
+    log = str(tmp_path / "ids.jsonl")
+    prof = str(tmp_path / "prof")
+    jax.profiler.start_trace(prof)
+    try:
+        with tr.trace_to(log):
+            with tr.run_tracing(ObsSpec()):
+                with tr.span("outer"):
+                    with tr.span("inner"):
+                        tr.event("mark")
+            with tr.run_tracing(ObsSpec()):
+                with tr.span("second"):
+                    pass
+        with tr.span("untraced"):       # no JSONL tracer: annotation only
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    recs = {r["name"]: r for r in map(json.loads, open(log))}
+    outer, inner, second = recs["outer"], recs["inner"], recs["second"]
+    assert outer["parent"] is None and inner["parent"] == outer["id"]
+    assert inner["run"] == outer["run"] is not None
+    assert second["run"] not in (None, outer["run"])
+    assert recs["mark"]["parent"] == inner["id"]
+    assert recs["mark"]["run"] == outer["run"]
+
+    ann = _host_annotations(prof)
+    assert {"repro:outer", "repro:inner", "repro:second",
+            "repro:untraced"} <= set(ann)
+    o, i = ann["repro:outer"], ann["repro:inner"]
+    assert o.start_ns <= i.start_ns
+    assert i.start_ns + i.duration_ns <= o.start_ns + o.duration_ns
+    so, si = dict(o.stats), dict(i.stats)
+    assert (so["id"], so["run"]) == (outer["id"], outer["run"])
+    assert (si["id"], si["parent"], si["run"]) == (
+        inner["id"], outer["id"], outer["run"])
+    assert "parent" not in so
+
+
+def test_tracer_writes_on_close_only(tmp_path):
+    from repro.obs.trace import Tracer
+    path = tmp_path / "t.jsonl"
+    t = Tracer(str(path))
+    t.event("a", k=1)
+    t.span_record("s", 0, 5, {"x": np.float32(2.5)})
+    assert not path.exists() or path.stat().st_size == 0
+    t.close()
+    recs = [json.loads(ln) for ln in open(path)]
+    assert [r["name"] for r in recs] == ["repro-trace/v1", "a", "s"]
+    assert recs[1]["k"] == 1 and recs[2]["x"] == 2.5
+    t.close()                           # a second close writes nothing
+    assert len(open(path).readlines()) == 3
